@@ -48,7 +48,6 @@ def _child_main(full: bool) -> dict:
         distributed_nmf,
         overlap_model,
         ring_psum,
-        shard_map,
     )
 
     devs = jax.devices()
@@ -60,9 +59,9 @@ def _child_main(full: bool) -> dict:
     x = jax.random.normal(key, (p * 4, 13, 33))
 
     def _reduce(fn):
-        f = shard_map(
-            lambda xl: fn(xl.reshape(-1, 33)), mesh,
-            in_specs=(P("data"),), out_specs=P(), check_rep=False,
+        f = jax.shard_map(
+            lambda xl: fn(xl.reshape(-1, 33)), mesh=mesh,
+            in_specs=(P("data"),), out_specs=P(), check_vma=False,
         )
         return jax.jit(f)(x)
 
@@ -110,7 +109,7 @@ def _child_main(full: bool) -> dict:
 def _spawn_child(full: bool) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (os.path.join(repo_root, "src"), env.get("PYTHONPATH")) if p

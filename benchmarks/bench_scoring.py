@@ -44,8 +44,6 @@ def _measured_bytes(fn, *args) -> float | None:
     """XLA-reported HBM traffic for the compiled fn, when the backend says."""
     try:
         cost = jax.jit(fn).lower(*args).compile().cost_analysis()
-        if isinstance(cost, list):  # older jax returns one dict per device
-            cost = cost[0]
         return float(cost["bytes accessed"])
     except Exception:
         return None

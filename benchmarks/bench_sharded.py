@@ -119,7 +119,7 @@ def _child_main(full: bool) -> dict:
 def _spawn_child(full: bool) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (os.path.join(repo_root, "src"), env.get("PYTHONPATH")) if p

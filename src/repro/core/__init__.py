@@ -21,7 +21,11 @@ from .api import (  # noqa: F401
 )
 from .evalplane import Wave  # noqa: F401
 from .chunking import chunk_block, chunk_skip_mod, plan_worklists, rebalance  # noqa: F401
-from .compile_cache import cache_entry_count, enable_persistent_cache  # noqa: F401
+from .compile_cache import (  # noqa: F401
+    cache_entry_count,
+    enable_persistent_cache,
+    resolve_compile_cache,
+)
 from .coordinator import Bounds, FileCoordinator, InProcessCoordinator  # noqa: F401
 from .scheduler import ResourceEvent  # noqa: F401
 from .scoring import (  # noqa: F401

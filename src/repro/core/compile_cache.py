@@ -5,13 +5,19 @@ number of distinct compiled ``(batch, k_pad)`` shapes *within* one search;
 this module makes those few compilations survive *across* processes: with
 ``jax_compilation_cache_dir`` set, XLA executables are written to disk and
 the next search over the same data shape deserializes instead of
-recompiling — the dominant cold-start cost of the batched/sharded
-executors.
+recompiling — the dominant cold-start cost of every executor on a chip.
 
-JAX only persists entries above built-in time/size thresholds by default
-(tuned for multi-minute TPU compiles); ``enable_persistent_cache`` lowers
-both to zero so the second-long CPU/GPU compiles of the wavefront planes
-are cached too.
+``resolve_compile_cache`` is the one place the directory is chosen:
+
+  * ``JAX_COMPILATION_CACHE_DIR`` set — the cache lives in that
+    directory, and no other directory is set in code.
+  * unset — ``<checkout>/.jax_cache``, a fixed path found from this file
+    (the path is part of what makes a later process find the entries, so
+    it never depends on a temporary name, a process id or the time).
+
+Either way JAX's persistence thresholds (tuned to skip compiles under a
+second) are lowered to zero, so the second-long compiles of the wavefront
+planes are cached too.
 
 This is deliberately config-only — no jax device state is touched at
 import time, so ``repro.core`` stays importable before XLA_FLAGS tricks
@@ -20,6 +26,10 @@ like ``--xla_force_host_platform_device_count``.
 from __future__ import annotations
 
 import os
+from pathlib import Path
+
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
 def enable_persistent_cache(
@@ -29,22 +39,30 @@ def enable_persistent_cache(
 ) -> bool:
     """Point jax's persistent compilation cache at ``cache_dir``.
 
-    Returns True if the cache was configured, False if this jax build does
-    not expose the config knobs (older/stripped builds) — callers treat
-    False as "run without a cache", never as an error. Call before the
-    first jit dispatch; entries compiled earlier are not retro-cached.
+    Call before the first jit dispatch; entries compiled earlier are not
+    retro-cached. Returns True.
     """
     import jax
 
     os.makedirs(cache_dir, exist_ok=True)
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # persist everything: the default thresholds skip sub-second compiles
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", min_compile_time_secs)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", min_entry_size_bytes)
-    except (AttributeError, ValueError):  # pragma: no cover - jax without the knobs
-        return False
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    # persist everything: the default thresholds skip sub-second compiles
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", min_compile_time_secs)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", min_entry_size_bytes)
     return True
+
+
+def resolve_compile_cache() -> str:
+    """Turn the persistent compile cache on; returns the directory in use.
+
+    Call first, before anything compiles (see the module docstring for
+    where the directory comes from).
+    """
+    # the variable's own directory is re-applied rather than trusted to
+    # jax's import-time read, which misses a variable set after import
+    cache_dir = os.environ.get(CACHE_ENV) or str(DEFAULT_CACHE_DIR)
+    enable_persistent_cache(cache_dir)
+    return cache_dir
 
 
 def cache_entry_count(cache_dir: str) -> int:
@@ -55,4 +73,10 @@ def cache_entry_count(cache_dir: str) -> int:
         return 0
 
 
-__all__ = ["enable_persistent_cache", "cache_entry_count"]
+__all__ = [
+    "CACHE_ENV",
+    "DEFAULT_CACHE_DIR",
+    "cache_entry_count",
+    "enable_persistent_cache",
+    "resolve_compile_cache",
+]

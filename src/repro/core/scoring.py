@@ -195,6 +195,7 @@ def silhouette_samples_masked(
     num_clusters: int,
     point_mask: Array | None = None,
     use_kernel: bool = False,
+    own_sums: Array | None = None,
 ) -> Array:
     """Per-point silhouette values; padding points and clusters are zeroed.
 
@@ -205,6 +206,13 @@ def silhouette_samples_masked(
     fit — never appear in b(i) and contribute nothing. Returns s (..., n);
     both the mean score and NMFk's per-cluster min reduce from this one
     streamed dist-sums pass.
+
+    ``own_sums`` (..., n), when given, replaces each point's own-cluster
+    distance sum. The streamed sums come from ``||x||^2 + ||y||^2 - 2 x.y``,
+    whose absolute error is about eps * ||x||^2; the sqrt turns that into
+    an error of about sqrt(eps) for near-duplicate points, which is exactly
+    what a tight cluster holds. A caller that can afford the difference
+    form for the own cluster (NMFk: p members per cluster) passes it here.
     """
     mask = (
         jnp.ones(x.shape[:-1], bool)
@@ -217,8 +225,9 @@ def silhouette_samples_masked(
     # padding points contribute nothing without ever masking distances
     dist_sums = cluster_dist_sums(x, onehot, use_kernel=use_kernel)  # (..., n, k)
     own_size = jnp.take_along_axis(sizes[..., None, :], labels[..., None], axis=-1)[..., 0]
-    own_sum = jnp.take_along_axis(dist_sums, labels[..., None], axis=-1)[..., 0]
-    a = own_sum / jnp.maximum(own_size - 1.0, 1.0)
+    if own_sums is None:
+        own_sums = jnp.take_along_axis(dist_sums, labels[..., None], axis=-1)[..., 0]
+    a = own_sums / jnp.maximum(own_size - 1.0, 1.0)
     mean_to = dist_sums / jnp.maximum(sizes[..., None, :], 1.0)
     mask_own = jax.nn.one_hot(labels, num_clusters, dtype=bool)
     empty = sizes[..., None, :] == 0  # includes every padded cluster slot

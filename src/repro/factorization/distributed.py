@@ -34,59 +34,14 @@ parallel-over-k × distributed-within-k composition.
 from __future__ import annotations
 
 import functools
-import inspect
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.6 stable API
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 COMM_MODES = ("sync", "pipelined")
-
-
-def _resolve_unreplicated_kwarg(fn) -> str:
-    """Which kwarg disables shard_map's replication check for ``fn``.
-
-    jax < 0.7 spells it ``check_rep``; newer jax renamed it ``check_vma``.
-    Resolved ONCE at import time from the signature — the shim used to
-    re-probe via a try/except TypeError on every call, which both paid the
-    probe per dispatch and masked unrelated TypeErrors from the first
-    spelling.
-    """
-    try:
-        params = inspect.signature(fn).parameters
-    except (TypeError, ValueError):  # pragma: no cover - C-level callable
-        return "check_rep"
-    if "check_rep" in params:
-        return "check_rep"
-    if "check_vma" in params:
-        return "check_vma"
-    if any(p.kind is inspect.Parameter.VAR_KEYWORD for p in params.values()):
-        # opaque **kwargs wrapper: assume the modern spelling
-        return "check_vma"
-    return "check_rep"  # pragma: no cover - neither spelling: fail loudly later
-
-
-_CHECK_KWARG = _resolve_unreplicated_kwarg(_shard_map)
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_rep: bool = True):
-    """Version shim. ``check_rep=False`` is needed where the replication of
-    an output can't be statically inferred (e.g. scores derived from RNG +
-    all_gather in the sharded NMFk plane) — newer jax renamed the kwarg,
-    and ``_CHECK_KWARG`` holds the spelling this jax supports."""
-    if check_rep:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **{_CHECK_KWARG: False}
-    )
-
 
 Array = jax.Array
 _EPS = 1e-9
@@ -336,15 +291,15 @@ def distributed_nmf(
     W-update (one-sweep-stale H; see the module docstring).
     """
     axis_size = dict(mesh.shape)[axis]
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _dnmf_local, k=k, iters=iters, axis=axis, comm=comm, axis_size=axis_size
         ),
-        mesh,
+        mesh=mesh,
         in_specs=(P(axis, None), P()),
         out_specs=(P(axis, None), P(), P()),
         # the ring gather's replication is invisible to rep inference
-        check_rep=(comm == "sync" or axis_size == 1),
+        check_vma=(comm == "sync" or axis_size == 1),
     )
     v = jax.device_put(v, NamedSharding(mesh, P(axis, None)))
     w, h, err = jax.jit(fn)(v, key)
@@ -414,9 +369,9 @@ def distributed_rescal(
     axis: str = "data",
 ) -> DistRESCALResult:
     """Entity-row-distributed RESCAL under `mesh`."""
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_drescal_local, k=k, iters=iters, axis=axis),
-        mesh,
+        mesh=mesh,
         in_specs=(P(None, axis, None), P()),
         out_specs=(P(axis, None), P(), P()),
     )
@@ -508,8 +463,21 @@ def _dnmf_masked_chunk_local(
     return w_l, h, err
 
 
+def auto_mesh(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis ``AxisType.Auto``.
+
+    ``jax.make_mesh`` defaults to explicit axes, whose arrays carry their
+    sharding in the type, so eager host-side indexing of a plane's outputs
+    (``scores[:n_real]``, slot writes ``.at[i].set``) is refused. The
+    shard_map'd fits here are written for automatic sharding propagation.
+    """
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names, axis_types=(AxisType.Auto,) * mesh.devices.ndim)
+
+
 def make_local_mesh(n_devices: int | None = None, axis: str = "data") -> Mesh:
     """1-D mesh over available devices (tests run this with 1 CPU device)."""
     devs = jax.devices()
     n = n_devices or len(devs)
-    return jax.make_mesh((n,), (axis,), devices=devs[:n])
+    return jax.make_mesh((n,), (axis,), (AxisType.Auto,), devices=devs[:n])

@@ -104,7 +104,10 @@ def nmfk_score(
     cols = jnp.transpose(w_all, (0, 2, 1)).reshape(-1, v.shape[0])  # (p*k, n)
     # one streamed dist-sums pass yields both statistics (the pooled-column
     # distance matrix is never materialized on the blocked/Pallas tiers)
-    s = silhouette_samples_masked(cols, labels, num_clusters=k, use_kernel=use_kernel)
+    s = silhouette_samples_masked(
+        cols, labels, num_clusters=k, use_kernel=use_kernel,
+        own_sums=_own_cluster_dist_sums(cols, labels, n_perturbs),
+    )
     sil_mean = jnp.mean(s)
     onehot = jax.nn.one_hot(labels, k, dtype=cols.dtype)
     sizes = jnp.sum(onehot, axis=0)
@@ -113,6 +116,28 @@ def nmfk_score(
     min_sil = jnp.where(k > 1, jnp.min(per_cluster), 1.0)
     sil_mean = jnp.where(k > 1, sil_mean, 1.0)
     return NMFkScore(min_sil, sil_mean, jnp.mean(errs))
+
+
+def _own_cluster_dist_sums(cols: Array, labels: Array, n_perturbs: int) -> Array:
+    """Exact own-cluster distance sums of the pooled columns, (p*k,).
+
+    cols (p*k, n) holds perturbation q's column j at row q*k + j, and the
+    alignment makes ``labels`` a permutation of the k clusters within each
+    perturbation, so every cluster has exactly one member per perturbation.
+    The p x p distances inside each cluster are then cheap enough to take
+    in the difference form ``||x - y||``, which stays accurate for the
+    near-duplicate columns of a stable rank, where the streamed Gram form
+    loses about sqrt(eps) to cancellation (see ``silhouette_samples_masked``).
+    """
+    p = n_perturbs
+    k = cols.shape[0] // p
+    x = cols.reshape(p, k, -1)
+    lab = labels.reshape(p, k)
+    # members[q, c] = perturbation q's column in cluster c
+    members = jnp.take_along_axis(x, jnp.argsort(lab, axis=1)[..., None], axis=1)
+    diff = members[:, None] - members[None, :]  # (p, p, k, n)
+    per_cluster = jnp.sum(jnp.sqrt(jnp.sum(diff * diff, axis=-1)), axis=1)  # (p, k)
+    return jnp.take_along_axis(per_cluster, lab, axis=1).reshape(p * k)
 
 
 def _align_columns_masked(w_all: Array, k_eff: Array) -> Array:
@@ -162,7 +187,8 @@ def _pooled_w_score(
     # one streamed dist-sums pass yields both statistics: mean over active
     # points and NMFk's per-cluster min over active clusters
     s = silhouette_samples_masked(
-        cols, labels, num_clusters=k_pad, point_mask=point_mask, use_kernel=use_kernel
+        cols, labels, num_clusters=k_pad, point_mask=point_mask, use_kernel=use_kernel,
+        own_sums=_own_cluster_dist_sums(cols, labels, n_perturbs),
     )
     sil_mean = jnp.sum(s) / jnp.maximum(jnp.sum(point_mask), 1.0)
     onehot = jax.nn.one_hot(labels, k_pad, dtype=cols.dtype) * point_mask[:, None]
@@ -304,8 +330,6 @@ def _sharded_score_fn(
     """
     from jax.sharding import PartitionSpec as P
 
-    from .distributed import shard_map
-
     shape = dict(mesh.shape)
     data = shape.get(data_axis, 1)
 
@@ -334,9 +358,13 @@ def _sharded_score_fn(
         in_specs = (P(lane_axis), P(lane_axis, None), P(data_axis, None))
 
     out_specs = NMFkScore(P(lane_axis), P(lane_axis), P(lane_axis))
-    # data-sharded scores are replicated over the data axis (all_gather'd W,
-    # psum'd errors) but rep inference can't see through the RNG draws
-    return jax.jit(shard_map(body, mesh, in_specs, out_specs, check_rep=(data == 1)))
+    # unchecked: data-sharded scores are replicated over the data axis
+    # (all_gather'd W, psum'd errors) but vma inference can't see through the
+    # RNG draws, and the column alignment's fori_loop carries unvarying
+    # initial values that come back lane-varying
+    return jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    ))
 
 
 def nmfk_score_sharded(
@@ -371,10 +399,11 @@ def nmfk_score_sharded(
     bucketing the batch to a lane multiple) and, when data > 1, v's row
     count divisible by the data-axis size.
     """
-    from .distributed import COMM_MODES
+    from .distributed import COMM_MODES, auto_mesh
 
     if comm not in COMM_MODES:
         raise ValueError(f"comm must be one of {COMM_MODES}, got {comm!r}")
+    mesh = auto_mesh(mesh)
     ks_arr, keys, k_pad = batched_lanes(ks, key, k_pad)
     shape = dict(mesh.shape)
     lanes = shape[lane_axis]
@@ -513,7 +542,7 @@ def _elastic_chunk_sharded_fn(
     """
     from jax.sharding import PartitionSpec as P
 
-    from .distributed import _dnmf_masked_chunk_local, shard_map
+    from .distributed import _dnmf_masked_chunk_local
     from .nmf import _masked_sweeps
 
     shape = dict(mesh.shape)
@@ -559,7 +588,10 @@ def _elastic_chunk_sharded_fn(
         # the RNG draws defeat replication inference
         out_specs = (P(lane_axis, data_axis), P(lane_axis), P(lane_axis))
 
-    return jax.jit(shard_map(body, mesh, in_specs, out_specs, check_rep=(data == 1)))
+    # unchecked, as in _sharded_score_fn
+    return jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    ))
 
 
 def elastic_chunk_sharded(
@@ -584,6 +616,9 @@ def elastic_chunk_sharded(
     v's rows divisible by the data-axis size (the elastic plane's slot
     bucketing guarantees the former).
     """
+    from .distributed import auto_mesh
+
+    mesh = auto_mesh(mesh)
     lanes = dict(mesh.shape)[lane_axis]
     if w.shape[0] % lanes:
         raise ValueError(f"lane batch {w.shape[0]} not divisible by lane count {lanes}")

@@ -56,6 +56,17 @@ from .nmfk import nmfk_score_batched, nmfk_score_sharded
 Array = jax.Array
 
 
+def _place(x: Array, mesh, *spec) -> Array:
+    """``x`` laid out as ``PartitionSpec(*spec)`` over ``mesh`` (no-op
+    without a mesh), so that sharded dispatches read arrays already spread
+    over the mesh instead of copying them from the first device each call."""
+    if mesh is None:
+        return x
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    return jax.device_put(x, NamedSharding(mesh, PartitionSpec(*spec)))
+
+
 class _BatchPlaneBase:
     """Shared padding / bucketing / accounting for the batched planes."""
 
@@ -69,13 +80,13 @@ class _BatchPlaneBase:
         bucket_min: int | None = None,
         comm: str = "sync",
     ):
-        from .distributed import COMM_MODES
+        from .distributed import COMM_MODES, auto_mesh
 
         if comm not in COMM_MODES:
             raise ValueError(f"comm must be one of {COMM_MODES}, got {comm!r}")
         self.k_pad = k_pad
         self.pad_batch = pad_batch
-        self.mesh = mesh
+        self.mesh = None if mesh is None else auto_mesh(mesh)
         self.comm = comm
         self.lane_axis = lane_axis
         self.data_axis = data_axis
@@ -225,7 +236,7 @@ class NMFkBatchPlane(_BatchPlaneBase):
             raise ValueError(
                 f"v rows {v.shape[0]} not divisible by data-axis size {self.data_count}"
             )
-        self.v = v
+        self.v = _place(v, self.mesh, data_axis if self.data_count > 1 else None)
         self.key = key
         self.n_perturbs = n_perturbs
         self.nmf_iters = nmf_iters
@@ -405,7 +416,6 @@ class KMeansBatchPlane(_BatchPlaneBase):
 
         from repro.core.scoring import davies_bouldin_score_masked, silhouette_score_masked
 
-        from .distributed import shard_map
         from .kmeans import _kmeans_masked
 
         score, max_iters, use_kernel = self.score, self.max_iters, self.use_kernel
@@ -422,11 +432,11 @@ class KMeansBatchPlane(_BatchPlaneBase):
                 )
             return silhouette_score_masked(x, res.labels, k_pad, use_kernel=use_kernel)
 
-        fn = jax.jit(shard_map(
-            body, self.mesh,
+        fn = jax.jit(jax.shard_map(
+            body, mesh=self.mesh,
             in_specs=(P(lane), P(lane, None), P()),
             out_specs=P(lane),
-            check_rep=False,  # scores replicated only over trivial axes; RNG defeats inference
+            check_vma=False,  # scores replicated only over trivial axes; RNG defeats inference
         ))
         self._sharded_fns[k_pad] = fn
         return fn
@@ -610,7 +620,7 @@ class NMFkElasticPlane:
         comm: str = "sync",
     ):
         from .batching import WarmStartCache, next_pow2
-        from .distributed import COMM_MODES
+        from .distributed import COMM_MODES, auto_mesh
 
         if statistic not in ("min", "mean"):
             raise ValueError(f"statistic must be 'min' or 'mean', got {statistic!r}")
@@ -623,6 +633,8 @@ class NMFkElasticPlane:
         shape = dict(mesh.shape) if mesh is not None else {}
         if mesh is not None and lane_axis not in shape:
             raise ValueError(f"mesh {mesh} has no {lane_axis!r} axis")
+        if mesh is not None:
+            mesh = auto_mesh(mesh)
         self.lane_count = shape.get(lane_axis, 1)
         self.data_count = shape.get(data_axis, 1)
         if self.data_count > 1 and v.shape[0] % self.data_count:
@@ -633,7 +645,10 @@ class NMFkElasticPlane:
             slots = round_up_multiple(next_pow2(max(2 * n_perturbs, self.lane_count)), self.lane_count)
         if slots < 1 or slots % max(self.lane_count, 1):
             raise ValueError(f"slots={slots} must be a positive multiple of lane count {self.lane_count}")
-        self.v = v
+        # on a mesh, V and the slot pool live where the shard_map'd chunk
+        # reads them: lanes over the lane axis, rows over the data axis
+        row_axis = data_axis if self.data_count > 1 else None
+        self.v = _place(v, mesh, row_axis)
         self.key = key
         self.n_perturbs = int(n_perturbs)
         self.nmf_iters = int(nmf_iters)
@@ -652,10 +667,12 @@ class NMFkElasticPlane:
         self.warm_cache = WarmStartCache(window=warm_window)
 
         n, m = v.shape
-        self._w = jnp.zeros((self.slots, n, self.k_pad), v.dtype)
-        self._h = jnp.zeros((self.slots, self.k_pad, m), v.dtype)
-        self._keff = jnp.zeros((self.slots,), jnp.int32)
-        self._pkeys = jnp.zeros((self.slots, 2), jnp.uint32)
+        self._w = _place(
+            jnp.zeros((self.slots, n, self.k_pad), v.dtype), mesh, lane_axis, row_axis
+        )
+        self._h = _place(jnp.zeros((self.slots, self.k_pad, m), v.dtype), mesh, lane_axis)
+        self._keff = _place(jnp.zeros((self.slots,), jnp.int32), mesh, lane_axis)
+        self._pkeys = _place(jnp.zeros((self.slots, 2), jnp.uint32), mesh, lane_axis)
         self._slot: list[_Lane | None] = [None] * self.slots
         self._n_occ = 0
         self._queue: deque[tuple[int, int]] = deque()
@@ -672,6 +689,11 @@ class NMFkElasticPlane:
         self.last_lane_utilization: float | None = None  # alias for scheduler gauges
 
     # -- scheduler surface -------------------------------------------------------
+    @property
+    def pool(self) -> tuple[Array, Array, Array, Array]:
+        """The slot pool's device buffers: (w, h, k_eff, pkeys)."""
+        return self._w, self._h, self._keff, self._pkeys
+
     @property
     def backlog(self) -> int:
         """Queued lanes not yet slotted (admission signal for the refiller)."""

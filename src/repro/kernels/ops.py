@@ -1,13 +1,14 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Handle padding to MXU/lane alignment, dtype plumbing, and interpret-mode
-fallback (this container is CPU-only; on CPU the kernels execute their
-Python bodies under ``interpret=True`` — bit-identical logic, same BlockSpec
-walk — while on TPU the same code lowers to Mosaic).
+Handle padding to the chip's tiling, dtype plumbing, and the choice of
+interpret mode. On the TPU every block is 128 wide in its last two
+dimensions (Mosaic requires multiples of 8 and 128 there), so n, m, d and
+the rank are zero-padded up to multiples of 128 — exact for the MU updates
+and for distances, see each wrapper. On the CPU the kernels execute their
+Python bodies under ``interpret=True`` (same BlockSpec walk), with 8-wide
+blocks wherever 128 does not divide, which keeps the CPU tests fast.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -19,14 +20,27 @@ from . import silhouette_sums as _ss
 
 
 def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret on the CPU, lower to Mosaic on the TPU; no other backend."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"Pallas kernels run on the TPU or interpreted on the CPU, not on {backend!r}"
+        )
+    return backend == "cpu"
 
 
 def _lane_mult(interpret: bool) -> int:
-    """Rank/lane padding multiple: the 128-lane MXU width on the real TPU
-    path, 8 under interpret mode where lane alignment buys nothing and
+    """Rank/lane padding multiple: the 128-lane MXU width on the TPU path,
+    8 under interpret mode, where lane alignment buys nothing and
     128-padding tiny-k problems would only waste interpreter time."""
     return 8 if interpret else 128
+
+
+def _block(size: int, interpret: bool) -> int:
+    """Block edge along a tiled axis of ``size``: always 128 on the TPU
+    path (the wrappers pad up to it); under interpret mode 128 where it
+    divides and 8 otherwise, so the interpreter walks few grid steps."""
+    return 128 if not interpret or size % 128 == 0 else 8
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
@@ -43,14 +57,14 @@ def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
 # NMF multiplicative updates
 # -----------------------------------------------------------------------------
 def mu_update_h(v: jax.Array, w: jax.Array, h: jax.Array, interpret: bool | None = None) -> jax.Array:
-    """Fused H <- H * (W^T V)/(W^T W H + eps); pads (n, m) to tiles and k to
-    the lane width (128 on TPU, 8 under interpret — see ``_lane_mult``)."""
+    """Fused H <- H * (W^T V)/(W^T W H + eps); pads n and m to the block
+    (``_block``) and k to the lane width (``_lane_mult``). Zero rows of V
+    and W add nothing to W^T V or W^T W, and zero columns of H stay zero,
+    so the padding is exact."""
     interpret = _interpret_default() if interpret is None else interpret
     n, m = v.shape
     k = w.shape[1]
-    bn = 128 if n % 128 == 0 else 8
-    bm = 128 if m % 128 == 0 else 8
-    bk = _lane_mult(interpret)
+    bn, bm, bk = _block(n, interpret), _block(m, interpret), _lane_mult(interpret)
     vp = _pad_to(_pad_to(v, 0, bn), 1, bm)
     wp = _pad_to(_pad_to(w, 0, bn), 1, bk)
     hp = _pad_to(_pad_to(h, 0, bk), 1, bm)
@@ -60,13 +74,11 @@ def mu_update_h(v: jax.Array, w: jax.Array, h: jax.Array, interpret: bool | None
 
 
 def mu_update_w(v: jax.Array, w: jax.Array, h: jax.Array, interpret: bool | None = None) -> jax.Array:
-    """Fused W <- W * (V H^T)/(W H H^T + eps); k padded like ``mu_update_h``."""
+    """Fused W <- W * (V H^T)/(W H H^T + eps); padded like ``mu_update_h``."""
     interpret = _interpret_default() if interpret is None else interpret
     n, m = v.shape
     k = w.shape[1]
-    bn = 128 if n % 128 == 0 else 8
-    bm = 128 if m % 128 == 0 else 8
-    bk = _lane_mult(interpret)
+    bn, bm, bk = _block(n, interpret), _block(m, interpret), _lane_mult(interpret)
     vp = _pad_to(_pad_to(v, 0, bn), 1, bm)
     wp = _pad_to(_pad_to(w, 0, bn), 1, bk)
     hp = _pad_to(_pad_to(h, 0, bk), 1, bm)
@@ -81,11 +93,8 @@ def mu_update_w(v: jax.Array, w: jax.Array, h: jax.Array, interpret: bool | None
 def pairwise_sq_dists(x: jax.Array, y: jax.Array | None = None, interpret: bool | None = None) -> jax.Array:
     interpret = _interpret_default() if interpret is None else interpret
     y = x if y is None else y
-    n, d = x.shape
-    m = y.shape[0]
-    bn = 128 if n % 128 == 0 else 8
-    bm = 128 if m % 128 == 0 else 8
-    bd = 128 if d % 128 == 0 else 8
+    (n, d), m = x.shape, y.shape[0]
+    bn, bm, bd = _block(n, interpret), _block(m, interpret), _block(d, interpret)
     xp = _pad_to(_pad_to(x, 0, bn), 1, bd)
     yp = _pad_to(_pad_to(y, 0, bm), 1, bd)
     out = _pd.pairwise_sq_dists(xp, yp, bn=bn, bm=bm, bd=bd, interpret=interpret)
@@ -103,11 +112,8 @@ def pairwise_sq_dists_batched(
     """
     interpret = _interpret_default() if interpret is None else interpret
     y = x if y is None else y
-    _, n, d = x.shape
-    m = y.shape[1]
-    bn = 128 if n % 128 == 0 else 8
-    bm = 128 if m % 128 == 0 else 8
-    bd = 128 if d % 128 == 0 else 8
+    (_, n, d), m = x.shape, y.shape[1]
+    bn, bm, bd = _block(n, interpret), _block(m, interpret), _block(d, interpret)
     xp = _pad_to(_pad_to(x, 1, bn), 2, bd)
     yp = _pad_to(_pad_to(y, 1, bm), 2, bd)
     out = _pd.pairwise_sq_dists_batched(xp, yp, bn=bn, bm=bm, bd=bd, interpret=interpret)
@@ -135,9 +141,7 @@ def silhouette_dist_sums(
     y = x if y is None else y
     n, d = x.shape
     m, k = onehot.shape
-    bn = 128 if n % 128 == 0 else 8
-    bm = 128 if m % 128 == 0 else 8
-    bd = 128 if d % 128 == 0 else 8
+    bn, bm, bd = _block(n, interpret), _block(m, interpret), _block(d, interpret)
     xp = _pad_to(_pad_to(x, 0, bn), 1, bd)
     yp = _pad_to(_pad_to(y, 0, bm), 1, bd)
     gp = _pad_to(_pad_to(onehot, 0, bm), 1, _lane_mult(interpret))
@@ -160,9 +164,7 @@ def silhouette_dist_sums_batched(
     y = x if y is None else y
     _, n, d = x.shape
     _, m, k = onehot.shape
-    bn = 128 if n % 128 == 0 else 8
-    bm = 128 if m % 128 == 0 else 8
-    bd = 128 if d % 128 == 0 else 8
+    bn, bm, bd = _block(n, interpret), _block(m, interpret), _block(d, interpret)
     xp = _pad_to(_pad_to(x, 1, bn), 2, bd)
     yp = _pad_to(_pad_to(y, 1, bm), 2, bd)
     gp = _pad_to(_pad_to(onehot, 1, bm), 2, _lane_mult(interpret))

@@ -8,9 +8,13 @@ devices (pyDNMFk mode). Pruning broadcasts flow through the coordinator
 (in-process for threads, file-based across hosts), and the journal makes
 the search restartable mid-flight.
 
-On this CPU container the sub-meshes are 1-device and resources are
-threads — the control plane is identical to the 512-chip layout; swap
-``make_submeshes`` for pod slices on real hardware.
+With fewer devices than resources (one chip, or the CPU) every resource
+shares the visible devices and resources are threads — the control plane
+is the one a pod runs; swap ``make_submeshes`` for pod slices there.
+
+The persistent compile cache is always on: it lives in
+``$JAX_COMPILATION_CACHE_DIR`` when that is set, else in ``.jax_cache/``
+at the checkout root (``repro.core.resolve_compile_cache``).
 
   PYTHONPATH=src python -m repro.launch.ksearch --k-max 16 --k-true 5 \
       --resources 4 --early-stop
@@ -51,8 +55,8 @@ from repro.core import (
     SearchSpace,
     ThreadPoolScheduler,
     WavefrontScheduler,
-    enable_persistent_cache,
     make_space,
+    resolve_compile_cache,
 )
 from repro.factorization.distributed import distributed_nmf, make_local_mesh
 from repro.factorization.nmfk import nmfk_score
@@ -131,9 +135,6 @@ def main(argv=None) -> dict:
                     help="seed refilled elastic lanes from the nearest "
                     "completed k's W (column pad/truncate + re-normalize); "
                     "--no-warm-start cold-starts every lane")
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="persistent jit compile cache dir: the handful of "
-                    "bucketed (batch, k_pad) shapes compile once across runs")
     ap.add_argument("--trace", default=None, metavar="OUT",
                     help="write a search trace: Chrome-trace/Perfetto JSON "
                     "(open at ui.perfetto.dev), or JSONL if OUT ends in .jsonl")
@@ -142,10 +143,8 @@ def main(argv=None) -> dict:
                     "histograms + pruning-efficiency block)")
     ap.add_argument("--quiet", action="store_true")
     args = ap.parse_args(argv)
-
-    if args.compile_cache:
-        # before the first jit dispatch: earlier compiles are not retro-cached
-        enable_persistent_cache(args.compile_cache)
+    # before the first jit dispatch: earlier compiles are not retro-cached
+    resolve_compile_cache()
 
     key = jax.random.PRNGKey(0)
     v, _, _ = nmf_data(key, n=args.n, m=args.m, k_true=args.k_true)
@@ -216,7 +215,11 @@ def _run_search(args, ap, space, v, key, evaluate):
             "lane_utilization_last": plane.last_lane_utilization,
         }
         if mesh is not None:
-            extra["mesh"] = {"lanes": plane.lane_count, "data": plane.data_count}
+            extra["mesh"] = {
+                "lanes": plane.lane_count, "data": plane.data_count,
+                "v_devices": _device_span(plane.v),
+                "pool_devices": _device_span(*plane.pool),
+            }
             extra["comm"] = args.comm
         return result, dt, extra
     if args.executor in ("batched", "sharded"):
@@ -248,7 +251,10 @@ def _run_search(args, ap, space, v, key, evaluate):
         dt = time.time() - t0
         extra = {"waves": sched.n_dispatches, "compiled_shapes": sorted(plane.shapes_compiled)}
         if mesh is not None:
-            extra["mesh"] = {"lanes": plane.lane_count, "data": plane.data_count}
+            extra["mesh"] = {
+                "lanes": plane.lane_count, "data": plane.data_count,
+                "v_devices": _device_span(plane.v),
+            }
             extra["lane_utilization_last"] = plane.last_lane_utilization
             extra["comm"] = args.comm
             if args.comm == "pipelined" and plane.data_count > 1:
@@ -270,6 +276,11 @@ def _run_search(args, ap, space, v, key, evaluate):
         dt = time.time() - t0
         extra = {"resources": args.resources}
     return result, dt, extra
+
+
+def _device_span(*arrays) -> int:
+    """Fewest devices any of ``arrays`` is laid out over."""
+    return min(len(x.sharding.device_set) for x in arrays)
 
 
 def _emit(args, result, dt, extra, tracer, metrics) -> dict:

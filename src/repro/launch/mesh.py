@@ -19,7 +19,7 @@ import threading
 from typing import Any, Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.models.layers import Axes
@@ -56,7 +56,9 @@ def make_wave_mesh(
     if need > len(devs):
         raise ValueError(f"mesh ({lanes} lanes x {data} data) needs {need} devices, "
                          f"have {len(devs)}")
-    return jax.make_mesh((lanes, data), ("lane", "data"), devices=devs[:need])
+    return jax.make_mesh(
+        (lanes, data), ("lane", "data"), (AxisType.Auto, AxisType.Auto), devices=devs[:need]
+    )
 
 
 class SubmeshPool:

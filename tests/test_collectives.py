@@ -9,8 +9,6 @@ real package is absent).
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -20,14 +18,11 @@ from hypothesis import strategies as st
 
 from repro.factorization import distributed
 from repro.factorization.distributed import (
-    _CHECK_KWARG,
     _dnmf_masked_local,
     _mu_sweeps,
-    _resolve_unreplicated_kwarg,
     distributed_nmf,
     overlap_model,
     ring_psum,
-    shard_map,
 )
 
 
@@ -148,60 +143,14 @@ def test_overlap_model_bounds_and_speedup():
     assert overlap_model(4096, 512, 8, data=4)["overlap_fraction"] == 1.0
 
 
-# ---------------------------------------------------------------------------
-# regression: check_rep/check_vma spelling resolved once at import
-# ---------------------------------------------------------------------------
-def test_resolve_unreplicated_kwarg_pins_both_spellings():
-    def old_api(f, mesh=None, in_specs=None, out_specs=None, check_rep=True):
-        pass
-
-    def new_api(f, mesh=None, in_specs=None, out_specs=None, check_vma=True):
-        pass
-
-    def opaque(f, **kwargs):
-        pass
-
-    def neither(f, mesh=None, in_specs=None, out_specs=None):
-        pass
-
-    assert _resolve_unreplicated_kwarg(old_api) == "check_rep"
-    assert _resolve_unreplicated_kwarg(new_api) == "check_vma"
-    assert _resolve_unreplicated_kwarg(opaque) == "check_vma"
-    assert _resolve_unreplicated_kwarg(neither) == "check_rep"
-
-
-def test_check_kwarg_matches_installed_jax():
-    """The import-time resolution must agree with the live shard_map: the
-    old per-call try/except probe is gone, so a wrong answer here would
-    TypeError on every unreplicated dispatch."""
-    params = inspect.signature(distributed._shard_map).parameters
-    has_var_kw = any(
-        p.kind is inspect.Parameter.VAR_KEYWORD for p in params.values()
-    )
-    assert _CHECK_KWARG in params or has_var_kw
-
-
-def test_shim_forwards_resolved_kwarg_once(monkeypatch):
-    calls = []
-
-    def fake(f, mesh=None, in_specs=None, out_specs=None, **kwargs):
-        calls.append(kwargs)
-        return f
-
-    monkeypatch.setattr(distributed, "_shard_map", fake)
-    shard_map(lambda x: x, mesh=None, in_specs=(), out_specs=())
-    shard_map(lambda x: x, mesh=None, in_specs=(), out_specs=(), check_rep=False)
-    assert calls[0] == {}  # replication check left on by default
-    assert calls[1] == {_CHECK_KWARG: False}  # single resolved spelling
-
-
 def test_shim_unreplicated_path_works_on_live_jax():
-    """End-to-end: the resolved spelling is one the installed jax accepts."""
+    """End-to-end: an unchecked (``check_vma=False``) shard_map with a
+    collective runs on the installed jax."""
     mesh = distributed.make_local_mesh(1)
     from jax.sharding import PartitionSpec as P
 
-    fn = shard_map(
-        lambda x: jax.lax.psum(x, "data"), mesh,
-        in_specs=(P(),), out_specs=P(), check_rep=False,
+    fn = jax.shard_map(
+        lambda x: jax.lax.psum(x, "data"), mesh=mesh,
+        in_specs=(P(),), out_specs=P(), check_vma=False,
     )
     np.testing.assert_allclose(jax.jit(fn)(jnp.arange(4.0)), jnp.arange(4.0))

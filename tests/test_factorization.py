@@ -126,3 +126,30 @@ def test_scores_prefer_k_true_on_blobs():
         db[k] = float(davies_bouldin_score(x, res.labels, k))
     assert sil[4] == max(sil.values())
     assert db[4] == min(db.values())
+
+
+def test_nmfk_own_cluster_sums_match_difference_form():
+    """The exact own-cluster sums equal the brute-force difference form
+    over each cluster's members, and sit closer to it than the streamed
+    Gram form does for near-duplicate columns (the case NMFk scores)."""
+    from repro.core.scoring import cluster_dist_sums
+    from repro.factorization.nmfk import _align_columns, _own_cluster_dist_sums
+
+    p, n, k = 4, 300, 6
+    base = jax.random.uniform(KEY, (n, k))
+    noise = 1e-3 * jax.random.normal(jax.random.fold_in(KEY, 1), (p, n, k))
+    perm = jnp.stack([jax.random.permutation(jax.random.fold_in(KEY, 2 + q), k) for q in range(p)])
+    w_all = jnp.stack([(base + noise[q])[:, perm[q]] for q in range(p)])
+    w_all = w_all / jnp.linalg.norm(w_all, axis=1, keepdims=True)
+    labels = _align_columns(w_all)
+    cols = jnp.transpose(w_all, (0, 2, 1)).reshape(-1, n)
+
+    got = _own_cluster_dist_sums(cols, labels, p)
+    x = np.asarray(cols, np.float64)
+    lab = np.asarray(labels)
+    dist = np.sqrt(((x[:, None] - x[None]) ** 2).sum(-1))
+    want = np.array([dist[i, lab == lab[i]].sum() for i in range(len(lab))])
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5)
+    onehot = jax.nn.one_hot(labels, k, dtype=cols.dtype)
+    gram = np.asarray(jnp.take_along_axis(cluster_dist_sums(cols, onehot), labels[:, None], 1)[:, 0])
+    assert np.max(np.abs(np.asarray(got) - want)) < np.max(np.abs(gram - want))

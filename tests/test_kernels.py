@@ -121,3 +121,17 @@ def test_kernel_nmf_path_matches_jnp_path():
     r1 = nmf(v, 4, KEY, iters=25)
     r2 = nmf(v, 4, KEY, iters=25, use_kernel=True)
     np.testing.assert_allclose(np.asarray(r1.w), np.asarray(r2.w), rtol=1e-3, atol=1e-4)
+
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False), ("gpu", None)])
+def test_interpret_default_by_backend(backend, interpret, monkeypatch):
+    """Interpret on the CPU, Mosaic on the TPU, and no silent interpreter
+    on any other backend."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            ops._interpret_default()
+    else:
+        assert ops._interpret_default() is interpret

@@ -126,6 +126,50 @@ def test_enable_persistent_cache_configures_jax(tmp_path):
         jax.config.update("jax_compilation_cache_dir", prev)
 
 
+_CACHE_CHILD = (
+    "import jax\n"
+    "from repro.core import resolve_compile_cache\n"
+    "print(resolve_compile_cache())\n"
+    "jax.block_until_ready(jax.jit(lambda x: x * 3.0 + 1.0)(jax.numpy.ones(7)))\n"
+)
+
+
+def _cache_child(env_dir: str | None) -> str:
+    """Resolve the cache and compile once in a fresh process (the cache
+    is initialized once per process); returns the directory it chose."""
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (os.path.join(REPO, "src"), env.get("PYTHONPATH")) if p
+    )
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    proc = subprocess.run(
+        [sys.executable, "-c", _CACHE_CHILD], capture_output=True, text=True,
+        env=env, cwd=REPO, timeout=300,
+    )
+    assert proc.returncode == 0, proc.stderr
+    return proc.stdout.strip().splitlines()[-1]
+
+
+def test_resolve_compile_cache_keeps_entries_in_env_dir(tmp_path):
+    from repro.core import cache_entry_count
+
+    cache = str(tmp_path / "from_env")
+    assert _cache_child(cache) == cache
+    # a sub-second compile is persisted: the thresholds were lowered
+    assert cache_entry_count(cache) > 0
+
+
+def test_resolve_compile_cache_defaults_to_checkout_root():
+    from repro.core import cache_entry_count
+    from repro.core.compile_cache import DEFAULT_CACHE_DIR
+
+    assert str(DEFAULT_CACHE_DIR) == os.path.join(os.path.realpath(REPO), ".jax_cache")
+    assert _cache_child(None) == str(DEFAULT_CACHE_DIR)
+    assert cache_entry_count(str(DEFAULT_CACHE_DIR)) > 0
+
+
 # ---------------------------------------------------------------------------
 # telemetry plumbing
 # ---------------------------------------------------------------------------
