@@ -272,6 +272,9 @@ class ElasticWavefrontScheduler:
       4. **evict** — cancel in-flight ks the new bounds prune (§III-D
          mid-fit abort, charged to ``ks_aborted`` / ``sweeps_saved``).
 
+    Each step is a span on the ``wavefront`` track (``admit``, ``tick``,
+    ``publish``, ``evict``), so under a tracer they cover the host loop.
+
     Like the wave executor, concurrency makes visits a superset of the
     serial schedule but a subset of the pre-order worklist; pruning
     soundness keeps ``k_optimal`` identical for threshold-separable score
@@ -299,44 +302,45 @@ class ElasticWavefrontScheduler:
         pos = 0
         self.n_ticks = 0
 
+        def admit_next() -> None:
+            nonlocal pos
+            k = worklist[pos]
+            pos += 1
+            if state.should_visit(k):
+                plane.submit(k)
+            else:
+                state.skip(k)
+
         while True:
             # 1. admit: refill the lane queue from the live worklist prefix
-            while pos < len(worklist) and policy.admit(plane):
-                k = worklist[pos]
-                pos += 1
-                if state.should_visit(k):
-                    plane.submit(k)
-                else:
-                    state.skip(k)
-            if plane.idle:
-                if pos >= len(worklist):
-                    break
+            with tracer.span("admit", track="wavefront"):
+                while pos < len(worklist) and policy.admit(plane):
+                    admit_next()
+                idle = plane.idle
                 # a refill policy must not starve an idle plane: force one
                 # admission so the loop always progresses
-                k = worklist[pos]
-                pos += 1
-                if state.should_visit(k):
-                    plane.submit(k)
-                else:
-                    state.skip(k)
-                continue
+                forced = idle and pos < len(worklist)
+                if forced:
+                    admit_next()
+            if idle:
+                if forced:
+                    continue
+                break
             # 2. tick: one chunk across all occupied lanes
             with tracer.span("tick", track="wavefront", tick=self.n_ticks):
                 finished = plane.tick()
             self.n_ticks += 1
-            occ = getattr(plane, "last_lane_occupancy", None)
-            if occ is not None:
-                metrics.set_gauge("lane_utilization", float(occ))
             # 3. record: fold completed scores into the prune bounds
             with tracer.span("publish", track="wavefront", tick=self.n_ticks - 1):
                 for k, score in finished:
                     state.record(k, float(score), resource=self.n_ticks - 1)
             # 4. evict: ks the updated bounds prune stop paying mid-fit
-            for k in sorted(plane.inflight_ks(), reverse=True):
-                if not state.should_visit(k) and plane.cancel(k):
-                    metrics.inc("ks_aborted")
-                    tracer.event("abort", track="wavefront", k=k)
-                    state.skip(k, reason="aborted")
+            with tracer.span("evict", track="wavefront"):
+                for k in sorted(plane.inflight_ks(), reverse=True):
+                    if not state.should_visit(k) and plane.cancel(k):
+                        metrics.inc("ks_aborted")
+                        tracer.event("abort", track="wavefront", k=k)
+                        state.skip(k, reason="aborted")
 
         return state.result()
 

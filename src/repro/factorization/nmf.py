@@ -156,8 +156,10 @@ def _masked_sweeps(
         live = s < steps
         return jnp.where(live, w, wh[0]), jnp.where(live, h, wh[1])
 
-    w, h = jax.lax.fori_loop(0, sweeps, body, (w, h))
-    err = jnp.linalg.norm(v - w @ h) / jnp.maximum(jnp.linalg.norm(v), _EPS)
+    with jax.named_scope("mu_update"):
+        w, h = jax.lax.fori_loop(0, sweeps, body, (w, h))
+    with jax.named_scope("rel_error"):
+        err = jnp.linalg.norm(v - w @ h) / jnp.maximum(jnp.linalg.norm(v), _EPS)
     return w, h, err
 
 

@@ -180,16 +180,18 @@ def _pooled_w_score(
     """Score a fitted perturbation ensemble: the shared tail of the masked
     scorers. w_all: (p, n, k_pad) raw W factors, errs: (p,) rel errors."""
     active = jnp.arange(k_pad) < k_eff
-    w_all = w_all / jnp.maximum(jnp.linalg.norm(w_all, axis=1, keepdims=True), 1e-12)
-    labels = _align_columns_masked(w_all, k_eff)  # (p*k_pad,)
+    with jax.named_scope("align_columns"):
+        w_all = w_all / jnp.maximum(jnp.linalg.norm(w_all, axis=1, keepdims=True), 1e-12)
+        labels = _align_columns_masked(w_all, k_eff)  # (p*k_pad,)
     cols = jnp.transpose(w_all, (0, 2, 1)).reshape(-1, w_all.shape[1])  # (p*k_pad, n)
     point_mask = jnp.tile(active, n_perturbs)  # (p*k_pad,)
     # one streamed dist-sums pass yields both statistics: mean over active
     # points and NMFk's per-cluster min over active clusters
-    s = silhouette_samples_masked(
-        cols, labels, num_clusters=k_pad, point_mask=point_mask, use_kernel=use_kernel,
-        own_sums=_own_cluster_dist_sums(cols, labels, n_perturbs),
-    )
+    with jax.named_scope("silhouette"):
+        s = silhouette_samples_masked(
+            cols, labels, num_clusters=k_pad, point_mask=point_mask, use_kernel=use_kernel,
+            own_sums=_own_cluster_dist_sums(cols, labels, n_perturbs),
+        )
     sil_mean = jnp.sum(s) / jnp.maximum(jnp.sum(point_mask), 1.0)
     onehot = jax.nn.one_hot(labels, k_pad, dtype=cols.dtype) * point_mask[:, None]
     sizes = jnp.sum(onehot, axis=0)
@@ -508,12 +510,14 @@ def elastic_chunk(
     chunk without a fresh compilation). Each lane regenerates its perturbed
     V from its pkey (cheaper than holding L perturbed copies of V in device
     memory) and reports the rel_error against it — the convergence signal
-    the tol gate tests host-side.
+    the tol gate tests host-side. Its ops carry the named scopes
+    ``perturb_v``, ``mu_update`` and ``rel_error`` in the profiler's trace.
     """
     from .nmf import _masked_sweeps
 
     def lane(w_i, h_i, k_i, st, pk):
-        vp = _perturb(pk, v, epsilon)
+        with jax.named_scope("perturb_v"):
+            vp = _perturb(pk, v, epsilon)
         return _masked_sweeps(
             vp, w_i, h_i, k_i, k_pad, chunk, use_kernel=use_kernel, steps=st
         )
@@ -639,7 +643,8 @@ def elastic_pooled_score(
     use_kernel: bool = False,
 ) -> NMFkScore:
     """Score a completed lane ensemble (p, n, k_pad) — the shared pooled-
-    column silhouette tail, jitted once per (k_pad, n_perturbs)."""
+    column silhouette tail, jitted once per (k_pad, n_perturbs). Its ops
+    carry the named scopes ``align_columns`` and ``silhouette``."""
     return _pooled_w_score(w_all, errs, k_eff, k_pad, n_perturbs, use_kernel)
 
 

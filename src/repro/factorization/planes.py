@@ -328,7 +328,6 @@ class NMFkBatchPlane(_BatchPlaneBase):
 
         model = overlap_model(self.v.shape[0], self.v.shape[1], k_pad, self.data_count)
         get_metrics().set_gauge("overlap_fraction", model["overlap_fraction"])
-        get_metrics().observe("overlap_fraction_hist", model["overlap_fraction"])
         if not tracer.enabled:
             return
         dur = max(tracer.now_us() - t0_us, 0.0)
@@ -686,7 +685,6 @@ class NMFkElasticPlane:
         self.n_ticks = 0
         self.shapes_compiled: set[tuple[int, int]] = set()
         self.last_lane_occupancy: float | None = None
-        self.last_lane_utilization: float | None = None  # alias for scheduler gauges
 
     # -- scheduler surface -------------------------------------------------------
     @property
@@ -750,10 +748,20 @@ class NMFkElasticPlane:
 
     def tick(self) -> list[tuple[int, float]]:
         """Refill freed slots, advance every occupied lane one chunk, retire
-        converged / budget-exhausted lanes; returns newly scored (k, score)."""
+        converged / budget-exhausted lanes; returns newly scored (k, score).
+
+        Under an enabled tracer the phases are spans: ``refill``, ``chunk``
+        (holding ``readback``, the blocking read of the lane errors) and
+        ``retire`` (holding one ``score`` per scored k). ``host_syncs``
+        counts the tick's blocking device-to-host reads: one per occupied
+        lane's error, one per scored k.
+        """
         tracer = get_tracer()
         metrics = get_metrics()
-        self._refill()
+        with tracer.span("refill", track="wavefront") as refill:
+            warm, cold = self._refill()
+            if tracer.enabled:
+                refill.set(slotted=warm + cold, warm=warm, cold=cold)
         if self._n_occ == 0:
             out, self._ready = self._ready, []
             return out
@@ -776,43 +784,52 @@ class NMFkElasticPlane:
         ]
         occupancy = n_occ / batch
         self.last_lane_occupancy = occupancy
-        self.last_lane_utilization = occupancy
         metrics.observe("lane_occupancy", occupancy)
-        metrics.set_gauge("lane_occupancy", occupancy)
-        with tracer.span(
-            "chunk", track=self._dispatch_track(), kind="nmfk_elastic", batch=batch,
-            n_occ=n_occ, k_pad=self.k_pad, sweeps=max(steps_host),
-            ks=sorted({self._slot[i].k for i in range(n_occ)}),
-        ):
+        track = self._dispatch_track()
+        attrs = self._chunk_attrs(batch, n_occ, steps_host) if tracer.enabled else {}
+        with tracer.span("chunk", track=track, **attrs):
             w_new, h_new, errs = self._dispatch(batch, jnp.asarray(steps_host, jnp.int32))
-            errs_host = [float(e) for e in errs[:n_occ]]
-        self._w = jnp.concatenate([w_new, self._w[batch:]], axis=0)
-        self._h = jnp.concatenate([h_new, self._h[batch:]], axis=0)
+            with tracer.span("readback", track=track):
+                errs_host = [float(e) for e in errs[:n_occ]]
 
-        retire: list[int] = []
-        for i in range(n_occ):
-            lane = self._slot[i]
-            st = steps_host[i]
-            lane.done += st
-            self.sweeps_run += st
-            metrics.inc("sweeps_run", st)
-            err = errs_host[i]
-            converged = self.tol > 0 and (lane.prev_err - err) < self.tol
-            lane.prev_err = err
-            if converged or lane.done >= self.nmf_iters:
-                if lane.done < self.nmf_iters:
-                    self._credit_saved(self.nmf_iters - lane.done)
-                retire.append(i)
-        for i in sorted(retire, reverse=True):
-            lane = self._slot[i]
-            self._finish_lane(lane, self._w[i], errs_host[i])
-            self._free_slot(i)
+        with tracer.span("retire", track="wavefront"):
+            self._w = jnp.concatenate([w_new, self._w[batch:]], axis=0)
+            self._h = jnp.concatenate([h_new, self._h[batch:]], axis=0)
+            swept = sum(steps_host)
+            self.sweeps_run += swept
+            metrics.inc("sweeps_run", swept)
+            retire: list[int] = []
+            for i in range(n_occ):
+                lane = self._slot[i]
+                lane.done += steps_host[i]
+                err = errs_host[i]
+                converged = self.tol > 0 and (lane.prev_err - err) < self.tol
+                lane.prev_err = err
+                if converged or lane.done >= self.nmf_iters:
+                    if lane.done < self.nmf_iters:
+                        self._credit_saved(self.nmf_iters - lane.done)
+                    retire.append(i)
+            for i in sorted(retire, reverse=True):
+                lane = self._slot[i]
+                self._finish_lane(lane, self._w[i], errs_host[i])
+                self._free_slot(i)
         out, self._ready = self._ready, []
+        metrics.inc("host_syncs", n_occ + len(out))
         return out
 
     # -- internals ---------------------------------------------------------------
     def _dispatch_track(self) -> str:
         return "device:all" if self.mesh is not None else "device:0"
+
+    def _chunk_attrs(self, batch: int, n_occ: int, steps_host: list[int]) -> dict:
+        """The ``chunk`` span's attributes: the dispatch's shape, its largest
+        sweep count and distinct ranks, and each occupied lane's k and sweeps."""
+        lane_ks = [self._slot[i].k for i in range(n_occ)]
+        return dict(
+            kind="nmfk_elastic", batch=batch, n_occ=n_occ, k_pad=self.k_pad,
+            sweeps=max(steps_host), ks=sorted(set(lane_ks)), lane_ks=lane_ks,
+            lane_steps=steps_host[:n_occ],
+        )
 
     def _credit_saved(self, sweeps: int) -> None:
         if sweeps > 0:
@@ -835,10 +852,12 @@ class NMFkElasticPlane:
             use_kernel=self.use_kernel,
         )
 
-    def _refill(self) -> None:
+    def _refill(self) -> tuple[int, int]:
+        """Slot queued lanes into free slots; returns (warm, cold) lanes slotted."""
         from .nmfk import elastic_lane_init, elastic_lane_warm_init
 
         metrics = get_metrics()
+        warm = cold = 0
         while self._queue and self._n_occ < self.slots:
             k, p = self._queue.popleft()
             task = self._tasks[k]
@@ -855,10 +874,12 @@ class NMFkElasticPlane:
                 metrics.inc("warm_start_hits")
                 get_tracer().event("warm_start", track=self._dispatch_track(),
                                    k=k, p=p, k_src=int(k_src))
+                warm += 1
             else:
                 w0, h0 = elastic_lane_init(
                     self.v, kj, task.pkeys[p], task.fkeys[p], self.k_pad, self.epsilon
                 )
+                cold += 1
             i = self._n_occ
             self._w = self._w.at[i].set(w0)
             self._h = self._h.at[i].set(h0)
@@ -866,6 +887,7 @@ class NMFkElasticPlane:
             self._pkeys = self._pkeys.at[i].set(task.pkeys[p])
             self._slot[i] = _Lane(k=k, p=p)
             self._n_occ += 1
+        return warm, cold
 
     def _free_slot(self, i: int) -> None:
         """Compact: move the last occupied lane into freed slot i."""
@@ -888,15 +910,16 @@ class NMFkElasticPlane:
         self.warm_cache.put(lane.k, lane.p, w_row)
         if len(task.w_parts) < self.n_perturbs or task.cancelled:
             return
-        w_all = jnp.stack([task.w_parts[p] for p in range(self.n_perturbs)])
-        errs = jnp.asarray(
-            [task.errs[p] for p in range(self.n_perturbs)], self.v.dtype
-        )
-        sc = elastic_pooled_score(
-            w_all, errs, jnp.asarray(lane.k), self.k_pad, self.n_perturbs,
-            self.use_kernel,
-        )
-        score = float(sc.min_silhouette if self.statistic == "min" else sc.mean_silhouette)
+        with get_tracer().span("score", track="wavefront", k=lane.k):
+            w_all = jnp.stack([task.w_parts[p] for p in range(self.n_perturbs)])
+            errs = jnp.asarray(
+                [task.errs[p] for p in range(self.n_perturbs)], self.v.dtype
+            )
+            sc = elastic_pooled_score(
+                w_all, errs, jnp.asarray(lane.k), self.k_pad, self.n_perturbs,
+                self.use_kernel,
+            )
+            score = float(sc.min_silhouette if self.statistic == "min" else sc.mean_silhouette)
         task.scored = True
         task.w_parts.clear()  # the warm cache holds what future ks need
         self._ready.append((lane.k, score))

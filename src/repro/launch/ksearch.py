@@ -212,7 +212,6 @@ def _run_search(args, ap, space, v, key, evaluate):
             "sweeps_fixed_total": plane.sweeps_fixed_total,
             "warm_start_hits": plane.warm_cache.hits,
             "lane_occupancy": plane.last_lane_occupancy,
-            "lane_utilization_last": plane.last_lane_utilization,
         }
         if mesh is not None:
             extra["mesh"] = {

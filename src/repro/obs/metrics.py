@@ -18,14 +18,15 @@ Conventional names used across the instrumented layers:
              speculations, failures, joins,
              sweeps_run / sweeps_saved / sweeps_fixed_total (the elastic
              executor's MU-sweep accounting: run + saved == fixed_total),
-             warm_start_hits (elastic lanes seeded from a neighbor's W)
+             warm_start_hits (elastic lanes seeded from a neighbor's W),
+             host_syncs (the elastic loop's blocking device-to-host reads:
+             one per occupied lane's error each chunk, one per scored k)
   gauges     ks_candidates, heartbeat_age_max, lo_bound, hi_bound,
              lane_utilization (real / dispatched lanes of the last wave),
-             lane_occupancy (occupied / dispatched lanes of the last
-             elastic chunk)
+             overlap_fraction (modelled, pipelined collectives)
   histograms wave_size, fit_seconds, publish_latency_s, lock_wait_s,
              lane_utilization (per-dispatch distribution),
-             lane_occupancy (per-chunk distribution)
+             lane_occupancy (occupied / dispatched lanes, per elastic chunk)
 """
 from __future__ import annotations
 

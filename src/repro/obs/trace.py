@@ -2,13 +2,21 @@
 
 Design constraints (this module is imported by the hot search path):
 
-  * **dependency-free** — stdlib only, importable from any layer;
+  * **dependency-free** — stdlib only, importable from any layer; once the
+    process has imported JAX, every span of an enabled ``Tracer`` also
+    opens a ``jax.profiler.TraceAnnotation`` of its name, so a running
+    profiler session records it as a host event beside the device ops
+    (same nesting, the profiler's clock);
   * **allocation-free when off** — the default tracer is a singleton
     ``NullTracer`` whose ``span()`` returns one shared no-op context
     manager and whose ``event()`` is a bare ``pass``;
   * **thread-safe when on** — workers of ``ThreadPoolScheduler`` and the
     wavefront loop append to one buffer under a lock (appends are tiny
     dicts; the model fits they bracket are milliseconds-to-minutes).
+
+Every span record carries an ``id`` and the ``parent`` id of the span
+that was open on the same thread when it began (None at the top), so a
+span's self time is its duration minus its children's.
 
 Span/event records carry a ``track`` — the timeline they belong to
 ("resource-3", "wavefront", "device:0"). The Perfetto export maps each
@@ -25,6 +33,7 @@ from __future__ import annotations
 import contextlib
 import json
 import math
+import sys
 import threading
 import time
 from typing import Any, Callable, Iterator
@@ -43,10 +52,21 @@ def _json_safe(v: Any) -> Any:
     return str(v)
 
 
+def _profiler_annotation(name: str):
+    """An entered ``jax.profiler.TraceAnnotation`` named ``name``, or None
+    when the process has not imported JAX (there is no profiler to join)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    annotation = jax.profiler.TraceAnnotation(name)
+    annotation.__enter__()
+    return annotation
+
+
 class Span:
     """One timed region; a context manager handed out by ``Tracer.span``."""
 
-    __slots__ = ("name", "track", "attrs", "ts_us", "_tracer")
+    __slots__ = ("name", "track", "attrs", "ts_us", "id", "parent", "_tracer", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, track: str | None, attrs: dict):
         self._tracer = tracer
@@ -54,6 +74,9 @@ class Span:
         self.track = track
         self.attrs = attrs
         self.ts_us = 0.0
+        self.id: int | None = None
+        self.parent: int | None = None
+        self._annotation = None
 
     def set(self, **attrs: Any) -> "Span":
         """Attach attributes discovered mid-span (e.g. the score)."""
@@ -61,11 +84,15 @@ class Span:
         return self
 
     def __enter__(self) -> "Span":
+        self._annotation = _profiler_annotation(self.name)
+        self._tracer._open(self)
         self.ts_us = self._tracer._now_us()
         return self
 
     def __exit__(self, *exc) -> None:
         self._tracer._complete(self)
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
 
 
 class _NullSpan:
@@ -119,9 +146,10 @@ class Tracer:
     """Buffered, thread-safe span/event recorder.
 
     Records are plain dicts:
-      spans  — ``{"name", "ph": "X", "ts", "dur", "track", "args"}``
+      spans  — ``{"name", "ph": "X", "ts", "dur", "track", "id", "parent", "args"}``
       events — ``{"name", "ph": "i", "ts", "track", "args"}``
-    (``ts``/``dur`` in microseconds since tracer creation.)
+    (``ts``/``dur`` in microseconds since tracer creation; ``parent`` is
+    the ``id`` of the span open on the same thread when the span began.)
     """
 
     enabled = True
@@ -131,6 +159,19 @@ class Tracer:
         self._t0 = clock()
         self._lock = threading.Lock()
         self._records: list[dict] = []
+        self._next_id = 0
+        self._open_spans = threading.local()  # per thread: ids of open spans
+
+    def _new_id(self) -> int:
+        with self._lock:
+            self._next_id += 1
+            return self._next_id
+
+    def _stack(self) -> list[int]:
+        stack = getattr(self._open_spans, "ids", None)
+        if stack is None:
+            stack = self._open_spans.ids = []
+        return stack
 
     # -- recording ------------------------------------------------------------
     def _now_us(self) -> float:
@@ -142,14 +183,23 @@ class Tracer:
         whose wall interval is only known after the batch completes)."""
         return self._now_us()
 
+    def _open(self, span: Span) -> None:
+        stack = self._stack()
+        span.id = self._new_id()
+        span.parent = stack[-1] if stack else None
+        stack.append(span.id)
+
     def _complete(self, span: Span) -> None:
         end = self._now_us()
+        self._stack().remove(span.id)
         rec = {
             "name": span.name,
             "ph": "X",
             "ts": span.ts_us,
             "dur": max(end - span.ts_us, 0.0),
             "track": span.track if span.track is not None else _current_track(),
+            "id": span.id,
+            "parent": span.parent,
             "args": span.attrs,
         }
         with self._lock:
@@ -179,6 +229,8 @@ class Tracer:
             "ts": float(ts_us),
             "dur": max(float(dur_us), 0.0),
             "track": track if track is not None else _current_track(),
+            "id": self._new_id(),
+            "parent": None,  # injected after the fact: nested in no open span
             "args": attrs,
         }
         with self._lock:
@@ -216,7 +268,8 @@ class Tracer:
         """Chrome-trace/Perfetto JSON object (``{"traceEvents": [...]}``).
 
         Tracks become tids (first-seen order) with ``thread_name`` metadata
-        so Perfetto shows one named lane per resource/worker.
+        so Perfetto shows one named lane per resource/worker. A span's
+        ``id`` and ``parent`` ride in its args as ``span_id``/``parent_id``.
         """
         recs = self.events()
         tids: dict[str, int] = {}
@@ -245,6 +298,7 @@ class Tracer:
             }
             if rec["ph"] == "X":
                 ev["dur"] = rec["dur"]
+                ev["args"].update(span_id=rec["id"], parent_id=rec["parent"])
             else:
                 ev["s"] = "t"  # instant scope: thread
             out.append(ev)

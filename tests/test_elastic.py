@@ -258,3 +258,127 @@ def test_batch_only_adapter_polls_abort_before_dispatch():
     assert calls == []  # pruned-while-queued k never paid for its fit
     assert plane.evaluate_one(5, should_abort=lambda: False) == 1.0
     assert calls == [[5]]
+
+
+# ---------------------------------------------------------------------------
+# the host loop under program spans, counters, and the profiler's trace
+# ---------------------------------------------------------------------------
+LOOP_SPANS = ("admit", "tick", "refill", "chunk", "readback", "retire", "score",
+              "publish", "evict")
+
+
+def _elastic_search_under(tracer):
+    """A small elastic search with ``tracer`` and a fresh metrics registry."""
+    from repro.obs import Metrics, use_metrics, use_tracer
+
+    metrics = Metrics()
+    with use_tracer(tracer), use_metrics(metrics):
+        plane = NMFkElasticPlane(
+            _fixture(), KEY, n_perturbs=3, nmf_iters=45, k_pad=6, tol=1e-4, chunk=15,
+            warm_start=True,
+        )
+        res = ElasticWavefrontScheduler(make_space((2, 6), 0.8)).run(plane)
+    return res, metrics
+
+
+def test_tick_spans_nest_and_host_syncs_count_every_read():
+    from repro.obs import Tracer
+
+    tracer = Tracer()
+    res, metrics = _elastic_search_under(tracer)
+    spans = [r for r in tracer.events() if r["ph"] == "X"]
+    by_id = {r["id"]: r for r in spans}
+    children: dict = {}
+    for r in spans:
+        children.setdefault(r["parent"], []).append(r)
+
+    def names(r):
+        return sorted(c["name"] for c in children.get(r["id"], []))
+
+    ticks = [r for r in spans if r["name"] == "tick"]
+    assert ticks
+    for tick in ticks:
+        # a tick whose refill leaves no lane occupied holds only its refill
+        assert names(tick) in (["chunk", "refill", "retire"], ["refill"])
+    chunks = [r for r in spans if r["name"] == "chunk"]
+    assert chunks and all(names(c) == ["readback"] for c in chunks)
+    for r in spans:
+        if r["name"] in ("admit", "tick", "publish", "evict"):
+            assert r["parent"] is None
+        elif r["name"] in ("refill", "chunk", "retire"):
+            assert by_id[r["parent"]]["name"] == "tick"
+        elif r["name"] == "score":
+            assert by_id[r["parent"]]["name"] == "retire"
+    scores = [r for r in spans if r["name"] == "score"]
+    assert len(scores) == len(res.visits) > 0
+    assert sorted(r["args"]["k"] for r in scores) == sorted(v.k for v in res.visits)
+    # per-lane (k, sweeps) of every dispatch
+    for c in chunks:
+        a = c["args"]
+        assert len(a["lane_ks"]) == len(a["lane_steps"]) == a["n_occ"]
+        assert sorted(set(a["lane_ks"])) == a["ks"] and max(a["lane_steps"]) == a["sweeps"]
+    assert sum(sum(c["args"]["lane_steps"]) for c in chunks) == metrics.counter("sweeps_run")
+    refills = [r["args"] for r in spans if r["name"] == "refill"]
+    assert all(a["slotted"] == a["warm"] + a["cold"] for a in refills)
+    assert sum(a["warm"] for a in refills) == metrics.counter("warm_start_hits") > 0
+    # one blocking read per occupied lane's error per chunk, one per scored k
+    assert metrics.counter("host_syncs") == sum(c["args"]["n_occ"] for c in chunks) + len(scores)
+
+
+def test_program_spans_are_host_events_in_the_profiler_trace(tmp_path):
+    import collections
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    from repro.obs import NULL_TRACER, Tracer
+
+    _elastic_search_under(NULL_TRACER)  # compile outside the profiled search
+    tracer = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _elastic_search_under(tracer)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"), recursive=True)
+    host = [
+        (ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+        for plane in ProfileData.from_file(path).planes if not plane.name.startswith("/device:")
+        for line in plane.lines for ev in line.events if ev.name in LOOP_SPANS
+    ]
+    recorded = collections.Counter(r["name"] for r in tracer.events() if r["ph"] == "X")
+    assert collections.Counter(name for name, _, _ in host) == recorded
+    assert all(recorded[name] > 0 for name in ("tick", "refill", "chunk", "readback",
+                                                "retire", "score"))
+    # the same nesting, on the profiler's clock
+    chunks = [(s, e) for name, s, e in host if name == "chunk"]
+    for name, s, e in host:
+        if name == "readback":
+            assert any(cs <= s and e <= ce for cs, ce in chunks)
+
+
+def test_null_tracer_records_nothing_and_opens_no_annotation(monkeypatch):
+    from repro.obs import NULL_TRACER, Tracer
+    from repro.obs import trace as trace_mod
+
+    opened = []
+    real = trace_mod._profiler_annotation
+
+    def counting(name):
+        opened.append(name)
+        return real(name)
+
+    def no_attrs(*args, **kwargs):
+        raise AssertionError("chunk span attributes built with tracing off")
+
+    monkeypatch.setattr(trace_mod, "_profiler_annotation", counting)
+    with monkeypatch.context() as m:
+        m.setattr(NMFkElasticPlane, "_chunk_attrs", no_attrs)
+        res, metrics = _elastic_search_under(NULL_TRACER)
+    assert res.k_optimal == 4
+    assert opened == [] and NULL_TRACER.events() == []
+    assert metrics.counter("host_syncs") > 0  # counters stay on without a tracer
+    tracer = Tracer()
+    _elastic_search_under(tracer)
+    assert sorted(opened) == sorted(r["name"] for r in tracer.events() if r["ph"] == "X")

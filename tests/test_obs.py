@@ -114,6 +114,56 @@ def test_export_perfetto_structure(tmp_path):
     assert instants and instants[0]["args"]["bound"] == "inf"
 
 
+def test_nested_spans_carry_parent_ids():
+    tr = Tracer()
+    with tr.span("tick"):
+        with tr.span("chunk"):
+            with tr.span("readback"):
+                pass
+        with tr.span("retire"):
+            def other_thread():
+                with tr.span("other-thread"):
+                    pass
+
+            worker = threading.Thread(target=other_thread)
+            worker.start()
+            worker.join(timeout=10)
+            assert not worker.is_alive()
+    with tr.span("publish"):
+        pass
+    recs = {r["name"]: r for r in tr.events()}
+    assert len({r["id"] for r in recs.values()}) == len(recs) == 6
+    assert recs["tick"]["parent"] is None and recs["publish"]["parent"] is None
+    assert recs["chunk"]["parent"] == recs["tick"]["id"] == recs["retire"]["parent"]
+    assert recs["readback"]["parent"] == recs["chunk"]["id"]
+    # the parent is the span open on the same thread, not on another one
+    assert recs["other-thread"]["parent"] is None
+    # self time of tick = its duration minus its children's
+    children = [r for r in recs.values() if r["parent"] == recs["tick"]["id"]]
+    assert recs["tick"]["dur"] >= sum(r["dur"] for r in children)
+
+
+def test_exports_carry_span_ids(tmp_path):
+    tr = Tracer()
+    with tr.span("tick"):
+        with tr.span("refill", k=3):
+            pass
+    tr.event("skip", k=9)
+    tr.add_span("lane", 0.0, 5.0, track="device:1")
+    jsonl = str(tmp_path / "t.jsonl")
+    tr.export_jsonl(jsonl)
+    lines = {r["name"]: r for r in map(json.loads, open(jsonl))}
+    assert lines["refill"]["parent"] == lines["tick"]["id"]
+    assert lines["tick"]["parent"] is None and lines["lane"]["parent"] is None
+    assert "id" not in lines["skip"]
+    perfetto = str(tmp_path / "t.json")
+    tr.export_perfetto(perfetto)
+    spans = {e["name"]: e for e in json.load(open(perfetto))["traceEvents"] if e["ph"] == "X"}
+    assert spans["refill"]["args"] == {"k": 3, "span_id": lines["refill"]["id"],
+                                       "parent_id": lines["tick"]["id"]}
+    assert spans["lane"]["args"]["parent_id"] is None
+
+
 # -- metrics primitives ---------------------------------------------------------
 
 
