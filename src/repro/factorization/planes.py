@@ -532,7 +532,42 @@ class KMeansBatchPlane(_BatchPlaneBase):
 # elastic plane: continuous batching of (k, perturbation) fit-chunks
 # ---------------------------------------------------------------------------
 import dataclasses
+import functools
 from collections import deque
+
+import numpy as np
+
+
+def _pool_compact(w, h, keff, pkeys, perm, w_new=None, h_new=None):
+    """The slot pool after a tick: ``w_new``/``h_new`` (a chunk's output,
+    if any) written over its ``[0:batch]`` prefix, then every array gathered
+    by ``perm`` along the slot axis (new slot i reads old slot ``perm[i]``).
+    Also returns the chunk's W rows, each its own buffer, so a row kept on
+    the host outlives the pool it came from."""
+    rows = ()
+    if w_new is not None:
+        batch = w_new.shape[0]
+        w = w.at[:batch].set(w_new)
+        h = h.at[:batch].set(h_new)
+        rows = tuple(w_new[i] for i in range(batch))
+    return (w[perm], h[perm], keff[perm], pkeys[perm]), rows
+
+
+@functools.lru_cache(maxsize=64)
+def _pool_compact_fn(shardings):
+    """``_pool_compact`` jitted with the pool donated and kept where it lies:
+    ``shardings`` holds each pool array's own sharding, or None for one
+    that no placement committed (left to follow its inputs, as eager ops
+    would), so a mesh's shard_map'd chunk reads the pool in place and
+    donation can reuse its buffers. Compiles once per (batch, k_pad, slots),
+    as the chunk program does, and once per slot count for a gather alone."""
+    return jax.jit(_pool_compact, donate_argnums=(0, 1, 2, 3),
+                   out_shardings=(shardings, None))
+
+
+def _pool_shardings(pool) -> tuple:
+    """The key of ``_pool_compact_fn`` for these pool arrays."""
+    return tuple(x.sharding if x.committed else None for x in pool)
 
 
 @dataclasses.dataclass
@@ -674,6 +709,9 @@ class NMFkElasticPlane:
         self._pkeys = _place(jnp.zeros((self.slots, 2), jnp.uint32), mesh, lane_axis)
         self._slot: list[_Lane | None] = [None] * self.slots
         self._n_occ = 0
+        # moves nothing: compiles the gather a cancel runs with the plane, so
+        # that no cancel compiles mid-search
+        self._compact_pool([])
         self._queue: deque[tuple[int, int]] = deque()
         self._tasks: dict[int, _KTask] = {}
         self._ready: list[tuple[int, float]] = []
@@ -735,13 +773,12 @@ class NMFkElasticPlane:
         if pending:
             self._queue = deque((kk, p) for kk, p in self._queue if kk != k)
             self._credit_saved(pending * self.nmf_iters)
-        evicted = 0
-        for i in range(self._n_occ - 1, -1, -1):
-            lane = self._slot[i]
-            if lane is not None and lane.k == k:
-                self._credit_saved(self.nmf_iters - lane.done)
-                self._free_slot(i)
-                evicted += 1
+        freed = [i for i in range(self._n_occ - 1, -1, -1) if self._slot[i].k == k]
+        for i in freed:
+            self._credit_saved(self.nmf_iters - self._slot[i].done)
+        evicted = len(freed)
+        if freed:
+            self._compact_pool(freed)
         get_tracer().event("evict", track=self._dispatch_track(), k=k,
                            pending=pending, evicted=evicted)
         return True
@@ -793,8 +830,6 @@ class NMFkElasticPlane:
                 errs_host = [float(e) for e in errs[:n_occ]]
 
         with tracer.span("retire", track="wavefront"):
-            self._w = jnp.concatenate([w_new, self._w[batch:]], axis=0)
-            self._h = jnp.concatenate([h_new, self._h[batch:]], axis=0)
             swept = sum(steps_host)
             self.sweeps_run += swept
             metrics.inc("sweeps_run", swept)
@@ -809,10 +844,11 @@ class NMFkElasticPlane:
                     if lane.done < self.nmf_iters:
                         self._credit_saved(self.nmf_iters - lane.done)
                     retire.append(i)
-            for i in sorted(retire, reverse=True):
-                lane = self._slot[i]
-                self._finish_lane(lane, self._w[i], errs_host[i])
-                self._free_slot(i)
+            freed = sorted(retire, reverse=True)
+            lanes = [self._slot[i] for i in freed]
+            rows = self._compact_pool(freed, w_new, h_new)
+            for i, lane in zip(freed, lanes):
+                self._finish_lane(lane, rows[i], errs_host[i])
         out, self._ready = self._ready, []
         metrics.inc("host_syncs", n_occ + len(out))
         return out
@@ -889,17 +925,28 @@ class NMFkElasticPlane:
             self._n_occ += 1
         return warm, cold
 
-    def _free_slot(self, i: int) -> None:
-        """Compact: move the last occupied lane into freed slot i."""
-        j = self._n_occ - 1
-        if i != j:
-            self._w = self._w.at[i].set(self._w[j])
-            self._h = self._h.at[i].set(self._h[j])
-            self._keff = self._keff.at[i].set(self._keff[j])
-            self._pkeys = self._pkeys.at[i].set(self._pkeys[j])
-            self._slot[i] = self._slot[j]
-        self._slot[j] = None
-        self._n_occ = j
+    def _compact_pool(self, freed: list[int], w_new=None, h_new=None) -> tuple:
+        """Free slots ``freed`` (descending) and keep the occupied slots a
+        prefix: each freed slot takes the last occupied lane. The moves are
+        replayed on the host's lane list into a source index per slot, and
+        the device pool follows in one donated call that also writes a
+        chunk's ``w_new``/``h_new`` over its prefix; returns that chunk's W
+        rows. ``pool_moves`` counts the lanes moved."""
+        perm = np.arange(self.slots, dtype=np.int32)
+        moves = 0
+        for i in freed:
+            j = self._n_occ - 1
+            if i != j:
+                perm[i] = perm[j]
+                self._slot[i] = self._slot[j]
+                moves += 1
+            self._slot[j] = None
+            self._n_occ = j
+        get_metrics().inc("pool_moves", moves)
+        fn = _pool_compact_fn(_pool_shardings(self.pool))
+        pool, rows = fn(*self.pool, perm, w_new, h_new)
+        self._w, self._h, self._keff, self._pkeys = pool
+        return rows
 
     def _finish_lane(self, lane: _Lane, w_row: Array, err: float) -> None:
         from .nmfk import elastic_pooled_score

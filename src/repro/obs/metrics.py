@@ -20,7 +20,9 @@ Conventional names used across the instrumented layers:
              executor's MU-sweep accounting: run + saved == fixed_total),
              warm_start_hits (elastic lanes seeded from a neighbor's W),
              host_syncs (the elastic loop's blocking device-to-host reads:
-             one per occupied lane's error each chunk, one per scored k)
+             one per occupied lane's error each chunk, one per scored k),
+             pool_moves (elastic lanes moved into a freed slot when the
+             slot pool is compacted, per tick and per evicting cancel)
   gauges     ks_candidates, heartbeat_age_max, lo_bound, hi_bound,
              lane_utilization (real / dispatched lanes of the last wave),
              overlap_fraction (modelled, pipelined collectives)

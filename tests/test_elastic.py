@@ -7,6 +7,7 @@ runs the identical draw schedule in chunks, so curves must agree exactly.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -174,6 +175,121 @@ def test_elastic_cancel_evicts_inflight_and_credits_saved():
     scores = _drain(plane)
     assert set(scores) == {4}
     assert plane.sweeps_run + plane.sweeps_saved == plane.sweeps_fixed_total
+
+
+# ---------------------------------------------------------------------------
+# slot pool compaction: one donated call against the eager per-lane copies
+# ---------------------------------------------------------------------------
+class _EagerCompactPlane(NMFkElasticPlane):
+    """The reference compaction: the chunk's output concatenated onto the
+    pool, then, per freed slot in descending order, the slot's W row sliced
+    out and the last occupied lane copied into it array by array."""
+
+    moves = 0
+
+    def _compact_pool(self, freed, w_new=None, h_new=None):
+        if w_new is not None:
+            batch = w_new.shape[0]
+            self._w = jnp.concatenate([w_new, self._w[batch:]], axis=0)
+            self._h = jnp.concatenate([h_new, self._h[batch:]], axis=0)
+        rows = {}
+        for i in freed:
+            rows[i] = self._w[i]
+            j = self._n_occ - 1
+            if i != j:
+                self._w = self._w.at[i].set(self._w[j])
+                self._h = self._h.at[i].set(self._h[j])
+                self._keff = self._keff.at[i].set(self._keff[j])
+                self._pkeys = self._pkeys.at[i].set(self._pkeys[j])
+                self._slot[i] = self._slot[j]
+                self.moves += 1
+            self._slot[j] = None
+            self._n_occ = j
+        return rows
+
+
+# lanes of one k converge chunks apart, so retirements free slots mid-pool
+COMPACT = dict(n_perturbs=3, nmf_iters=45, k_pad=6, tol=1e-3, chunk=4, slots=6,
+               warm_start=True)
+COMPACT_KS = [2, 3, 4, 5, 6]
+
+
+def _assert_same_pool(plane, ref):
+    for got, want in zip(plane.pool, ref.pool):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    lanes = [None if x is None else dataclasses.astuple(x) for x in plane._slot]
+    assert lanes == [None if x is None else dataclasses.astuple(x) for x in ref._slot]
+
+
+def _lockstep(planes, check):
+    """Submit COMPACT_KS to every plane and tick them together until idle;
+    after the third tick, cancel the k in flight in the first slot. Calls
+    ``check()`` after every tick and after the cancel; returns the scores
+    and the cancelled k."""
+    for p in planes:
+        for k in COMPACT_KS:
+            p.submit(k)
+    scores, ticks, cancelled = {}, 0, None
+    while not planes[0].idle:
+        outs = [p.tick() for p in planes]
+        assert all(out == outs[0] for out in outs)
+        scores.update(outs[0])
+        check()
+        ticks += 1
+        if ticks == 3:
+            cancelled = planes[0]._slot[0].k
+            assert all(p.cancel(cancelled) for p in planes)
+            check()
+    assert all(p.idle for p in planes)
+    return scores, cancelled
+
+
+def test_pool_compaction_is_bit_identical_to_eager_copies():
+    """After every tick, and after a cancel that evicts lanes mid-pool, the
+    pool, the lane order and every score equal the eager reference's bit for
+    bit: the compiled call only moves bytes."""
+    plane = NMFkElasticPlane(_fixture(), KEY, **COMPACT)
+    ref = _EagerCompactPlane(_fixture(), KEY, **COMPACT)
+    moves = []
+
+    def check():
+        _assert_same_pool(plane, ref)
+        moves.append(ref.moves)
+
+    scores, cancelled = _lockstep([plane, ref], check)
+    assert set(scores) == set(COMPACT_KS) - {cancelled}
+    assert moves[3] > moves[2]  # the cancel left holes before the tail
+    assert moves[-1] > moves[3] - moves[2]  # so did retirements
+
+
+def test_pool_donation_spares_retained_rows_and_compiles_per_bucket():
+    """Donating the pool never reaches a W row kept for scoring or warm
+    starts, the live pool stays readable, the compiled call has one variant
+    per chunk shape besides the cancel's gather, which the plane compiles
+    when it is built, and ``pool_moves`` counts the reference's moves."""
+    from repro.factorization.planes import _pool_compact_fn, _pool_shardings
+    from repro.obs import Metrics, use_metrics
+
+    ref = _EagerCompactPlane(_fixture(), KEY, **COMPACT)
+    fn = _pool_compact_fn(_pool_shardings(ref.pool))
+    fn.clear_cache()
+    plane = NMFkElasticPlane(_fixture(), KEY, **COMPACT)
+    assert fn._cache_size() == 1
+    kept = []
+
+    def check():
+        rows = [w for t in plane._tasks.values() for w in t.w_parts.values()]
+        rows += [w for by_p in plane.warm_cache._by_k.values() for w in by_p.values()]
+        assert not any(w.is_deleted() for w in rows)
+        kept.append(len(rows))
+
+    metrics = Metrics()
+    with use_metrics(metrics):
+        _lockstep([plane, ref], check)
+    assert max(kept) >= 3 * COMPACT["n_perturbs"]  # finished lanes' rows were checked
+    assert all(np.isfinite(np.asarray(x)).all() for x in plane.pool)
+    assert 1 < fn._cache_size() <= len(plane.shapes_compiled) + 1
+    assert metrics.counter("pool_moves") == ref.moves > 0
 
 
 def test_refill_policy_admits_up_to_backlog_cap():
